@@ -100,36 +100,21 @@ def _build_core(cfg: dict):
 
 
 # ----------------------------------------------------------- HTTP client
-async def _sse_terminal(resp):
-    """Minimal SSE reader: (terminal_kind, payload, n_previews)."""
-    name, previews = None, 0
-    async for raw in resp.content:
-        line = raw.decode("utf-8").rstrip("\r\n")
-        if line.startswith("event: "):
-            name = line[len("event: "):]
-        elif line.startswith("data: "):
-            if name == "preview":
-                previews += 1
-            elif name in ("result", "error"):
-                return name, json.loads(line[len("data: "):]), previews
-    return "error", {"error": "stream-closed", "status": 500}, previews
-
-
 async def _one(sess, url, spec, arrival, sched_t, loop, out):
+    from repro.launch.serve import sample_request
+
     delay = sched_t - loop.time()
     if delay > 0:
         await asyncio.sleep(delay)
     row = {"previews": 0, "arrival": arrival}
     try:
-        if spec.get("stream"):
-            async with sess.post(url, json=spec) as resp:
-                kind, body, previews = await _sse_terminal(resp)
-                row.update(kind=kind, body=body, previews=previews)
+        got = await sample_request(sess, url, spec)
+        if got["terminal"] is None:
+            row.update(kind="error",
+                       body={"error": "stream-closed", "status": 500})
         else:
-            async with sess.post(url, json=spec) as resp:
-                body = await resp.json()
-                row.update(kind="result" if resp.status == 200 else "error",
-                           body=body)
+            row.update(kind=got["terminal"], body=got["result"])
+        row["previews"] = got["previews"]
     except Exception as e:          # transport failure = hard error
         row.update(kind="error", body={"error": f"client:{e!r}"})
     row["latency_s"] = loop.time() - sched_t
@@ -140,7 +125,7 @@ async def _replay(port: int, specs):
     """``specs`` = [(arrival_s, spec_dict), ...]; real wall-clock pacing.
     Returns (rows, makespan_s) — makespan from first arrival to last
     terminal, the goodput denominator."""
-    url = f"http://127.0.0.1:{port}/v1/sample"
+    url = f"http://127.0.0.1:{port}"
     out = []
     loop = asyncio.get_running_loop()
     conn = aiohttp.TCPConnector(limit=0)   # never throttle arrivals
@@ -218,7 +203,7 @@ def run_load(cfg: dict) -> dict:
         # The sustained mid-window completion rate anchors the absolute
         # trace rates; the goodput GATE uses the no-control replay below
         # (same arrival churn as the measured run), not this number.
-        url = f"http://127.0.0.1:{port}/v1/sample"
+        url = f"http://127.0.0.1:{port}"
         menu, out = cfg["s_menu"], []
         workers = 3 * cfg["slots"] * len(core.fleet.pools)
         counter = itertools.count()

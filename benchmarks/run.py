@@ -78,6 +78,8 @@ import sys
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 PAPER_MODULES = [
     "benchmarks.table1_quality",
     "benchmarks.table2_reconstruction",
@@ -246,6 +248,7 @@ def main() -> None:
                     "BENCH_*.json baselines and append a dated entry to "
                     "BENCH_HISTORY.md")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.check and args.record:
         ap.error("--check and --record are mutually exclusive")

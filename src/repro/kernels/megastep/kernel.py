@@ -151,8 +151,10 @@ def _mega_kernel(coef_ref, t_ref, *refs, eps_jaxpr, n_leaves, n_consts, K,
     x = x_ref[...]
     for k in range(K):
         eps2 = eps_jaxpr(*consts, x, t_ref[k], *leaves)
+        # SMEM holds scalars only: read the row's coefficients one by one
+        coef = [coef_ref[k, j] for j in range(5)]
         x = _update(x.astype(jnp.float32), eps2.astype(jnp.float32),
-                    coef_ref[k], clip).astype(x.dtype)
+                    coef, clip).astype(x.dtype)
     out_ref[...] = x
 
 
@@ -168,7 +170,9 @@ def _mega_rows_kernel(coef_ref, t_ref, *refs, eps_jaxpr, n_leaves, n_consts,
     consts = [r[...] for r in refs[n_leaves:n_leaves + n_consts]]
     x_ref, out_ref = refs[n_leaves + n_consts], refs[n_leaves + n_consts + 1]
     x = x_ref[...]
-    eps2 = eps_jaxpr(*consts, x, t_ref[...], *leaves)
+    # SMEM holds scalars only: gather the per-slot timesteps one by one
+    t = jnp.stack([t_ref[b] for b in range(t_ref.shape[0])])
+    eps2 = eps_jaxpr(*consts, x, t, *leaves)
     _, out = _row_update(x.astype(jnp.float32), eps2.astype(jnp.float32),
                          coef_ref[...], clip, want_x0=False)
     out_ref[...] = out.astype(x.dtype)

@@ -39,6 +39,16 @@ MEGA_VMEM_BUDGET = 12 * 2 ** 20
 
 DEFAULT_K_FUSE = 8
 
+# Why the megakernel runs only in interpret mode: the TPU compiler (Mosaic,
+# JAX 0.9) cannot lower the trunk inside one kernel. Compiled for a v5e,
+# attn_impl='exact' fails on the layer scan over stacked weights (Pallas
+# scan lowering: NotImplementedError for extensive inputs) and 'flash' on
+# the float time-embedding iota ("'tpu.iota' op result #0 must be vector
+# of integer or index values").
+TPU_REFUSAL = ("the TPU compiler refuses the megakernel (layer scan with "
+               "stacked weights; float iota in the time embedding) — it "
+               "runs in interpret mode only")
+
 
 @dataclasses.dataclass
 class MegaSpec:
@@ -102,12 +112,16 @@ class MegaSpec:
 
 
 def eligible(spec: Optional[MegaSpec], x_T: jnp.ndarray,
-             budget: Optional[int] = None) -> Tuple[bool, str]:
+             budget: Optional[int] = None, *,
+             interpret: bool = True) -> Tuple[bool, str]:
     """(ok, reason) — can this (eps model, state) pair run the megakernel?
 
     Plan-level conditions (deterministic, order 1, no trajectory) are the
-    backend's to check; this covers the model/geometry/VMEM half.
+    backend's to check; this covers the model/geometry/VMEM half and the
+    compile target (compiled for the chip it is refused: TPU_REFUSAL).
     """
+    if not interpret:
+        return False, TPU_REFUSAL
     if spec is None:
         return False, "eps model carries no mega_spec (not a fused-capable "\
                       "tile-aware trunk)"

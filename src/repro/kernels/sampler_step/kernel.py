@@ -103,13 +103,19 @@ def sw_random_bits(seed, tid, salt: int, shape):
     return _fmix32((ctr ^ key) * _GOLDEN + key)
 
 
+def _u24_to_f32(bits):
+    """Top 24 bits of a uint32 draw as an exact float32 in [0, 2**24)."""
+    return jnp.right_shift(bits, np.uint32(8)).astype(jnp.int32).astype(
+        jnp.float32)
+
+
 def bits_to_normal(b1, b2):
     """Box-Muller: two uint32 draws -> one standard-normal float32."""
-    # 24-bit mantissa-sized uniforms in (0, 1), exclusive at both ends
-    u1 = (jnp.right_shift(b1, np.uint32(8)).astype(jnp.float32)
-          + 0.5) * np.float32(1.0 / 16777216.0)
-    u2 = jnp.right_shift(b2, np.uint32(8)).astype(jnp.float32) * np.float32(
-        1.0 / 16777216.0)
+    # 24-bit mantissa-sized uniforms in (0, 1), exclusive at both ends.
+    # The shifted values are below 2**24, so going through int32 is exact;
+    # Mosaic has no direct uint32 -> float32 cast.
+    u1 = (_u24_to_f32(b1) + 0.5) * np.float32(1.0 / 16777216.0)
+    u2 = _u24_to_f32(b2) * np.float32(1.0 / 16777216.0)
     return jnp.sqrt(-2.0 * jnp.log(u1)) * jnp.cos(
         np.float32(2.0 * np.pi) * u2)
 

@@ -26,7 +26,7 @@ import numpy as np
 
 from repro import configs
 from repro.launch import shapes as shp
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import V5E, make_production_mesh
 from repro.launch.roofline import (analyze, lm_model_flops, memory_report)
 from repro.models import get_api
 from repro.models.common import ArchConfig
@@ -194,7 +194,7 @@ def run_combo(arch_id: str, shape_id: str, multi_pod: bool,
     mflops = lm_model_flops(n_active, n_tokens,
                             "train" if combo.kind == "train" else "serve")
     hlo = compiled.as_text()
-    terms = analyze(compiled, hlo, n_chips, model_flops=mflops)
+    terms = analyze(compiled, hlo, n_chips, V5E, model_flops=mflops)
     rec["roofline"] = terms.as_dict()
     rec["total_s"] = round(time.time() - t0, 1)
     return rec

@@ -33,11 +33,12 @@ def make_host_mesh(model: int = 1):
     return jax.make_mesh((n // model, model), ("data", "model"))
 
 
-def make_fleet_mesh(n_pools: int, model: int = 1):
+def make_fleet_mesh(n_pools: int, model: int = 1, devices=None):
     """Split the local devices into ``n_pools`` disjoint pool meshes.
 
     Each pool gets its own ("data", "model") mesh over a contiguous,
-    non-overlapping slice of ``jax.devices()`` — the device-level view of
+    non-overlapping slice of ``devices`` (default ``jax.devices()``;
+    with as many devices as pools, one chip each) — the device-level view of
     a data-parallel slot-pool fleet (serving/fleet): tensor/data sharding
     INSIDE a pool, pure data parallelism ACROSS pools. Returns a list of
     ``n_pools`` meshes. CPU simulation recipe: force 8 host devices and
@@ -46,7 +47,7 @@ def make_fleet_mesh(n_pools: int, model: int = 1):
     import numpy as np
     from jax.sharding import Mesh
 
-    devs = jax.devices()
+    devs = list(jax.devices() if devices is None else devices)
     n = len(devs)
     if n_pools < 1 or n % n_pools:
         raise ValueError(
@@ -65,7 +66,19 @@ def make_fleet_mesh(n_pools: int, model: int = 1):
             for p in range(n_pools)]
 
 
-# Hardware constants for the roofline analysis (TPU v5e)
-PEAK_FLOPS_BF16 = 197e12      # per chip
-HBM_BW = 819e9                # bytes/s per chip
-ICI_BW = 50e9                 # bytes/s per link
+# Per-chip peaks for the roofline analysis, keyed by jax's
+# ``Device.device_kind``. Source: Google Cloud documentation, "TPU v5e":
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip
+# interconnect over 4 links (50 GB/s each).
+V5E = "TPU v5 lite"
+PEAKS = {
+    V5E: {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_link_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """The peak table row of ``device_kind``; an unknown kind is an error."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak numbers for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})")
+    return PEAKS[device_kind]

@@ -11,8 +11,7 @@ all-reduce / reduce-scatter / all-to-all / collective-permute instruction
 (a per-device byte count, since the partitioned HLO is the per-device
 program).
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s ICI
-per link.
+Peak numbers come from ``launch.mesh.peaks(device_kind)``.
 """
 from __future__ import annotations
 
@@ -21,7 +20,7 @@ import json
 import re
 from typing import Dict, Optional, Tuple
 
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
+from .mesh import peaks
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -83,10 +82,10 @@ class RooflineTerms:
         return dataclasses.asdict(self)
 
 
-def analyze(compiled, hlo_text: str, n_chips: int,
+def analyze(compiled, hlo_text: str, n_chips: int, device_kind: str,
             model_flops: Optional[float] = None,
             links_per_chip: float = 1.0) -> RooflineTerms:
-    """Loop-corrected roofline terms.
+    """Loop-corrected roofline terms against ``device_kind``'s peaks.
 
     Uses hlo_analysis.aggregate (walks the call graph with while-loop trip
     multiplicities) because raw cost_analysis counts lax.scan bodies ONCE,
@@ -99,9 +98,10 @@ def analyze(compiled, hlo_text: str, n_chips: int,
     cbytes = float(tot["coll_bytes_total"])
     coll = {k: int(v) for k, v in tot["coll_bytes"].items()}
     coll["count"] = int(tot["coll_count"])
-    compute_s = flops / PEAK_FLOPS_BF16
-    memory_s = byts / HBM_BW
-    collective_s = cbytes / (ICI_BW * links_per_chip)
+    peak = peaks(device_kind)
+    compute_s = flops / peak["flops_bf16"]
+    memory_s = byts / peak["hbm_bw"]
+    collective_s = cbytes / (peak["ici_link_bw"] * links_per_chip)
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     bottleneck = max(terms, key=terms.get)

@@ -10,6 +10,8 @@ lockstep batches or through the continuous-batching scheduler.
       --slots 4 --s-mix 10,20,50 --n-samples 12
   PYTHONPATH=src python -m repro.launch.serve --arch unet --gateway \
       --port 8807       # async HTTP/SSE front door (docs/gateway.md)
+  PYTHONPATH=src python -m repro.launch.serve --arch unet --gateway \
+      --unet cifar10    # the paper's CIFAR10 U-Net (32x32, 35.7M params)
 
 ``--gateway`` serves the U-Net fleet behind the async front door
 (serving/gateway): POST /v1/sample with ``"stream": true`` streams x0
@@ -30,6 +32,9 @@ with a p50/p95/p99 latency + miss/drop summary table.
 from __future__ import annotations
 
 import argparse
+import json
+import time
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +42,7 @@ import numpy as np
 
 from repro import configs
 from repro.core import make_schedule
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import get_api, unet
 from repro.obs import (JsonlSink, Observability, render_dashboard,
                        render_summary, summarize_results)
@@ -139,6 +145,108 @@ def serve_lm(args):
           f"throughput={results[0].tokens_per_s:.1f} tok/s")
 
 
+# the two U-Net configurations serve.py can select, with their image size
+UNETS = {"toy": (configs.TOY_UNET, 16), "cifar10": (configs.CIFAR10_UNET, 32)}
+
+
+def build_unet_gateway(ucfg: unet.UNetConfig, image_size: int,
+                       models: Dict[str, object], *, T: int = 1000,
+                       pools_per_model: int = 1, slots: int = 4,
+                       devices: Optional[Sequence] = None, obs=None,
+                       probes=None, flight_dir: Optional[str] = None,
+                       **engine_kw):
+    """The U-Net gateway ``--gateway`` serves: a GatewayCore over
+    ``pools_per_model`` slot pools per named weight set in ``models``.
+
+    When the pools divide ``devices`` (default ``jax.devices()``) evenly,
+    each pool gets its own mesh slice (launch.mesh.make_fleet_mesh) and
+    keeps its state and weights there — one chip per pool when there are
+    as many chips as pools. Otherwise every pool shares the default
+    device. ``engine_kw`` reaches every ContinuousBatchingEngine.
+    """
+    from repro.launch.mesh import make_fleet_mesh
+    from repro.serving.gateway import GatewayCore, OverloadPolicy
+
+    n_pools = len(models) * pools_per_model
+    devices = list(jax.devices() if devices is None else devices)
+    meshes = (make_fleet_mesh(n_pools, devices=devices)
+              if len(devices) % n_pools == 0 else None)
+    return GatewayCore.build(
+        make_schedule("linear", T=T),
+        lambda p, x, t: unet.forward(p, ucfg, x, t),
+        (image_size, image_size, 3), models=models,
+        pools_per_model=pools_per_model, slots=slots,
+        policy=OverloadPolicy(), obs=obs, probes=probes,
+        flight_dir=flight_dir, meshes=meshes, **engine_kw)
+
+
+def parse_sse(lines: List[str]) -> List[Tuple[str, Dict]]:
+    """SSE text lines -> [(event name, decoded data payload)]."""
+    out, name = [], None
+    for line in lines:
+        if line.startswith("event: "):
+            name = line[len("event: "):]
+        elif line.startswith("data: ") and name is not None:
+            out.append((name, json.loads(line[len("data: "):])))
+            name = None
+    return out
+
+
+async def sample_request(sess, url: str, spec: Dict) -> Dict:
+    """POST one spec to the gateway at base ``url``; returns {status,
+    events, terminal, result, previews, latency_s}. ``events`` lists
+    every event name the client saw; ``terminal`` is "result", "error"
+    or None (the stream closed without one); ``result`` is the terminal
+    payload (x0 as a numpy array)."""
+    t0 = time.perf_counter()
+    async with sess.post(f"{url}/v1/sample", json=spec) as r:
+        if spec.get("stream"):
+            lines = [raw.decode("utf-8").rstrip("\n")
+                     async for raw in r.content]
+            events = parse_sse(lines)
+        else:
+            body = await r.json()
+            events = [("result" if r.status == 200 else "error", body)]
+        status = r.status
+    latency = time.perf_counter() - t0
+    terminal = [(name, data) for name, data in events
+                if name in ("result", "error")]
+    name, result = terminal[-1] if terminal else (None, None)
+    if result is not None and "x0" in result:
+        x0 = result["x0"]
+        result = dict(result, x0=np.reshape(
+            np.asarray(x0["data"], np.float32), x0["shape"]))
+    return {"status": status, "events": [n for n, _ in events],
+            "terminal": name, "result": result,
+            "previews": sum(n == "preview" for n, _ in events),
+            "latency_s": latency}
+
+
+async def gateway_round_trip(core, specs: Sequence[Dict]):
+    """Serve ``core`` on an ephemeral port, send every spec at once
+    through a live aiohttp client, then stop the gateway.
+
+    Returns (outcomes in spec order, the /v1/stats body, the bridge —
+    whose ``.error`` says whether the engine thread died).
+    """
+    import asyncio
+
+    import aiohttp
+    from repro.serving.gateway import start_gateway, stop_gateway
+
+    runner, bridge, port = await start_gateway(core, port=0)
+    url = f"http://127.0.0.1:{port}"
+    try:
+        async with aiohttp.ClientSession() as sess:
+            outcomes = await asyncio.gather(
+                *[sample_request(sess, url, dict(s)) for s in specs])
+            async with sess.get(f"{url}/v1/stats") as r:
+                stats = await r.json()
+    finally:
+        await stop_gateway(runner, bridge)
+    return list(outcomes), stats, bridge
+
+
 def serve_unet_gateway(args):
     """--gateway: serve the U-Net through the async HTTP/SSE front door.
 
@@ -146,22 +254,16 @@ def serve_unet_gateway(args):
     with --ckpt the checkpoint's 'ema' and 'raw' weight sets become two
     routable models (same trunk, hot-swap-compatible); without one, two
     differently-seeded inits stand in ('base'/'alt'). Serves on --port
-    until Ctrl-C. --smoke binds an ephemeral port, round-trips one JSON
-    and one streaming SSE request per model through a live aiohttp
-    client, prints a one-line verdict, and exits non-zero on failure —
-    the tier-1 guard that this launch path can't rot.
+    until Ctrl-C. --smoke round-trips one JSON and one streaming SSE
+    request per model through a live aiohttp client, prints a one-line
+    verdict, and exits non-zero on failure — the tier-1 guard that this
+    launch path can't rot.
     """
     import asyncio
 
-    from repro.serving.gateway import HAVE_HTTP
-    if not HAVE_HTTP:
-        raise SystemExit("--gateway requires aiohttp for the HTTP/SSE "
-                         "transport (serving/gateway/http.py)")
-    from repro.serving.gateway import (GatewayCore, OverloadPolicy,
-                                       start_gateway, stop_gateway)
+    from repro.serving.gateway import start_gateway, stop_gateway
 
-    ucfg = configs.TOY_UNET
-    schedule = make_schedule("linear", T=args.T)
+    ucfg, image_size = UNETS[args.unet]
     base = unet.init_params(jax.random.PRNGKey(args.seed), ucfg)
     if args.ckpt:
         ref = {"params": base, "ema": base}
@@ -172,69 +274,48 @@ def serve_unet_gateway(args):
                   "alt": unet.init_params(jax.random.PRNGKey(args.seed + 1),
                                           ucfg)}
     obs, _ = _make_obs(args)
-    core = GatewayCore.build(
-        schedule, lambda p, x, t: unet.forward(p, ucfg, x, t),
-        (args.image_size, args.image_size, 3),
-        models=models, pools_per_model=max(1, args.pools),
-        slots=args.slots, policy=OverloadPolicy(), obs=obs,
+    core = build_unet_gateway(
+        ucfg, image_size, models, T=args.T,
+        pools_per_model=max(1, args.pools), slots=args.slots, obs=obs,
         probes=args.probes or None, flight_dir=args.flight_dir)
 
-    async def _smoke_client(port: int) -> bool:
-        import aiohttp
-        url = f"http://127.0.0.1:{port}"
-        async with aiohttp.ClientSession() as sess:
-            async with sess.get(f"{url}/v1/models") as r:
-                names = sorted(await r.json())
-            # JSON round-trip on one model, SSE previews on the other
-            spec = {"model": names[0], "S": 4, "seed": args.seed}
-            async with sess.post(f"{url}/v1/sample", json=spec) as r:
-                body = await r.json()
-                ok = r.status == 200 and body["event"] == "result"
-            spec = {"model": names[-1], "S": 6, "seed": args.seed + 1,
-                    "stream": True, "preview_every": 2}
-            previews = results = 0
-            async with sess.post(f"{url}/v1/sample", json=spec) as r:
-                async for raw in r.content:
-                    line = raw.decode("utf-8").strip()
-                    if line == "event: preview":
-                        previews += 1
-                    elif line == "event: result":
-                        results += 1
-            ok = ok and results == 1 and previews > 0
-            async with sess.get(f"{url}/v1/stats") as r:
-                st = await r.json()
+    if args.smoke:
+        names = sorted(models)
+        # JSON round-trip on one model, SSE previews on the other
+        specs = [{"model": names[0], "S": 4, "seed": args.seed},
+                 {"model": names[-1], "S": 6, "seed": args.seed + 1,
+                  "stream": True, "preview_every": 2}]
+        outcomes, st, bridge = asyncio.run(gateway_round_trip(core, specs))
+        js, sse = outcomes
+        ok = (js["status"] == 200 and js["events"] == ["result"]
+              and sse["events"].count("result") == 1
+              and sse["previews"] > 0 and bridge.error is None)
         print(f"gateway smoke: models={names} json+sse round-trips "
-              f"previews={previews} requests={st['requests']} "
+              f"previews={sse['previews']} requests={st['requests']} "
               f"({'OK' if ok else 'FAIL'})")
-        return ok
+        if not ok:
+            raise SystemExit(1)
+        return
 
-    async def _serve() -> int:
-        runner, bridge, port = await start_gateway(
-            core, port=0 if args.smoke else args.port)
-        if args.smoke:
-            ok = await _smoke_client(port)
-            await stop_gateway(runner, bridge)
-            return 0 if ok else 1
+    async def _serve() -> None:
+        runner, bridge, port = await start_gateway(core, port=args.port)
         print(f"gateway listening on http://127.0.0.1:{port} "
               f"(models: {sorted(models)}; Ctrl-C to stop)")
         try:
             await asyncio.Event().wait()
         finally:
             await stop_gateway(runner, bridge)
-        return 0
 
     try:
-        rc = asyncio.run(_serve())
+        asyncio.run(_serve())
     except KeyboardInterrupt:
-        rc = 0
-    if rc:
-        raise SystemExit(rc)
+        pass
 
 
 def serve_unet(args):
     if args.gateway:
         return serve_unet_gateway(args)
-    ucfg = configs.TOY_UNET
+    ucfg, image_size = UNETS[args.unet]
     schedule = make_schedule("linear", T=args.T)
     params = unet.init_params(jax.random.PRNGKey(args.seed), ucfg)
     if args.ckpt:
@@ -248,7 +329,7 @@ def serve_unet(args):
         bank = PlanBank.load(args.plan_bank, schedule)
         print(f"plan bank: {len(bank)} rows, NFE frontier {bank.nfes}")
     svc = DiffusionSampler(schedule, eps_fn,
-                           (args.image_size, args.image_size, 3),
+                           (image_size, image_size, 3),
                            batch_size=args.batch, plan_bank=bank)
     if args.scheduler:
         return serve_unet_continuous(args, svc)
@@ -397,13 +478,12 @@ def serve_unet_fleet(args, svc: DiffusionSampler, *, stochastic,
     s_mix = [int(s) for s in args.s_mix.split(",")]
     meshes = None
     n_dev = len(jax.devices())
-    if n_dev >= 2 * args.pools and n_dev % args.pools == 0:
+    if n_dev % args.pools == 0:
         from repro.launch.mesh import make_fleet_mesh
         meshes = make_fleet_mesh(args.pools)
     obs, trace_path = _make_obs(args)
     fleet = PoolFleet.build(
-        svc.schedule, svc.eps_fn,
-        (args.image_size, args.image_size, 3), n_pools=args.pools,
+        svc.schedule, svc.eps_fn, svc.shape, n_pools=args.pools,
         slots=args.slots, meshes=meshes, dtype=svc.dtype,
         stochastic=stochastic, max_order=max_order, clip_x0=clip_x0,
         plan_bank=svc.plan_bank, obs=obs,
@@ -439,7 +519,11 @@ def main():
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--n-samples", type=int, default=8)
-    ap.add_argument("--image-size", type=int, default=16)
+    ap.add_argument("--unet", choices=sorted(UNETS), default="toy",
+                    help="unet: the U-Net configuration to serve (toy: "
+                    "the CPU-trainable demo; cifar10: the paper's 35.7M-"
+                    "parameter CIFAR10 model), with its image size "
+                    "(16 and 32)")
     ap.add_argument("--T", type=int, default=1000)
     ap.add_argument("--S", type=int, default=20)
     ap.add_argument("--eta", type=float, default=0.0)
@@ -502,6 +586,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.gateway and args.arch != "unet":
         ap.error("--gateway serves the diffusion fleet; use --arch unet")
     if args.order > 1 and args.eta > 0.0 and not args.scheduler:

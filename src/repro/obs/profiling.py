@@ -7,8 +7,8 @@ counts state-sized array traffic analytically) and a REAL device profile:
   with ``Observability(profile=True)`` wrap every tick in
   ``annotate("repro/tick/<variant>")`` (variant = mega | rows |
   multistep), so a ``jax.profiler.trace(...)`` capture groups device time
-  under the same names the benchmarks report. No-op (and free) when the
-  profiler is unavailable or profiling is off.
+  under the same names the benchmarks report. Engines skip it entirely
+  when profiling is off.
 * :func:`modeled_hbm_table` — the per-tick modeled-HBM attribution for a
   live engine: which arrays the tick variant moves through HBM and how
   many bytes each, from the engine's actual geometry. Cross-check a
@@ -17,24 +17,17 @@ counts state-sized array traffic analytically) and a REAL device profile:
 """
 from __future__ import annotations
 
-import contextlib
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from jax import tree_util as _tree_util
-
-try:
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except ImportError:                                   # pragma: no cover
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 
 def annotate(name: str):
     """Context manager marking a host-side region in profiler traces."""
-    if _TraceAnnotation is None:                      # pragma: no cover
-        return contextlib.nullcontext()
-    return _TraceAnnotation(name)
+    return TraceAnnotation(name)
 
 
 def _itemsize(dtype) -> int:

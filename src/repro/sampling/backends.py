@@ -234,9 +234,9 @@ def run_mega(plan, eps_fn, x_T, rng, return_trajectory,
     """The megakernel path: trunk + update fused, K plan steps per launch.
 
     Eligibility is AUTOMATIC: a deterministic order-1 plan over an eps
-    model carrying a VMEM-fitting ``mega_spec`` runs fused; everything
-    else silently falls back to the tile-resident scan (identical
-    results — the fallback is the same arithmetic, unfused).
+    model carrying a VMEM-fitting ``mega_spec`` runs fused in interpret
+    mode; everything else (a compiled TPU run included) falls back to the
+    tile-resident scan (identical results — the same arithmetic, unfused).
 
     The chunk loop is UNROLLED so an S-step trajectory lowers to exactly
     ceil(S / K) pallas_call equations with the (R, C) state carried
@@ -248,13 +248,14 @@ def run_mega(plan, eps_fn, x_T, rng, return_trajectory,
     from repro.kernels import megastep as mega_ops
     from repro.kernels.sampler_step import ops as tile_ops
 
-    spec = getattr(eps_fn, "mega_spec", None)
-    ok, _why = mega_ops.eligible(spec, x_T)
-    if (not ok or plan.stochastic or plan.order > 1 or return_trajectory):
-        return run_tile_resident(plan, eps_fn, x_T, rng, return_trajectory,
-                                 interpret)
     if interpret is None:
         interpret = tile_ops.default_interpret()
+    spec = getattr(eps_fn, "mega_spec", None)
+    ok, _why = mega_ops.eligible(spec, x_T, interpret=interpret)
+    if (not ok or plan.stochastic or plan.order > 1
+            or return_trajectory):
+        return run_tile_resident(plan, eps_fn, x_T, rng, return_trajectory,
+                                 interpret)
     clip = plan.x0.clip
     tab = plan.steps()                       # sampling order, numpy
     S = plan.S

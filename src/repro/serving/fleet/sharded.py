@@ -34,7 +34,6 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.schedules import NoiseSchedule
@@ -110,9 +109,9 @@ def make_sharded_eps(mesh: Mesh, params) -> Callable:
     def local_apply(p, x, t):
         return trunk_apply(p, x, t, model_axis="model")
 
-    mapped = shard_map(local_apply, mesh=mesh,
-                       in_specs=(pspecs, P(data, None), P(data)),
-                       out_specs=P(data, None))
+    mapped = jax.shard_map(local_apply, mesh=mesh,
+                           in_specs=(pspecs, P(data, None), P(data)),
+                           out_specs=P(data, None))
 
     def eps_fn(x, t):
         return mapped(placed, x, t)

@@ -9,8 +9,7 @@ Layering (each importable on its own):
   registry.ModelRegistry  resident/staged checkpoints with versions
   bridge.EngineBridge     the one engine thread pumping the core +
                           a command queue (asyncio-safe call/acall)
-  http                    aiohttp HTTP/SSE transport (optional import —
-                          everything else works without aiohttp)
+  http                    aiohttp HTTP/SSE transport
 
 See docs/gateway.md for endpoints, the SSE event schema, the overload
 policy, and the hot-swap walkthrough.
@@ -18,15 +17,9 @@ policy, and the hot-swap walkthrough.
 from .admission import OverloadPolicy
 from .bridge import EngineBridge
 from .core import GatewayCore, parse_spec
+from .http import build_app, start_gateway, stop_gateway
 from .registry import ModelRegistry
 
-try:                                    # transport only with aiohttp
-    from .http import build_app, start_gateway, stop_gateway
-    HAVE_HTTP = True
-except ImportError:                     # pragma: no cover - env without it
-    HAVE_HTTP = False
-    build_app = start_gateway = stop_gateway = None
-
-__all__ = ["EngineBridge", "GatewayCore", "HAVE_HTTP", "ModelRegistry",
+__all__ = ["EngineBridge", "GatewayCore", "ModelRegistry",
            "OverloadPolicy", "build_app", "parse_spec", "start_gateway",
            "stop_gateway"]

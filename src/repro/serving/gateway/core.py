@@ -60,6 +60,7 @@ _SPEC_FIELDS = {
     "S": (int,),
     "eta": (int, float),
     "tau": (str,),
+    "order": (int,),
     "seed": (int,),
     "deadline_s": (int, float, type(None)),
     "preview_every": (int,),
@@ -96,12 +97,16 @@ def parse_spec(spec: Dict, request_id: int, now: float) -> SampleRequest:
     if preview_every < 0:
         raise RequestError(RejectCode.BAD_REQUEST,
                            "preview_every must be >= 0")
+    order = spec.get("order", 1)
+    if order < 1:
+        raise RequestError(RejectCode.BAD_REQUEST, "order must be >= 1")
     affinity = spec.get("affinity_key")
     return SampleRequest(
         request_id=request_id,
         S=spec.get("S", 20),
         eta=float(spec.get("eta", 0.0)),
         tau_kind=tau,
+        solver_order=order,
         auto_plan=spec.get("auto_plan", False),
         seed=spec.get("seed", 0),
         deadline=(now + float(deadline_s)
@@ -644,7 +649,8 @@ class GatewayCore:
               warm: bool = True, supervise: bool = True,
               breaker=None, checkpoint_every: int = 8,
               injector=None, probes=None, flight_dir: Optional[str] = None,
-              flight_capacity: int = 64, **engine_kw) -> "GatewayCore":
+              flight_capacity: int = 64, meshes: Optional[List] = None,
+              **engine_kw) -> "GatewayCore":
         """A multi-model gateway over fresh pools.
 
         ``eps_apply(params, x, t)`` is the shared trunk; ``models`` maps
@@ -669,9 +675,19 @@ class GatewayCore:
         written under ``flight_dir`` — in-memory only when None) feeding
         the quarantine/nonfinite dumps, ``/v1/debug/flight/{pool}``, the
         per-result ``quality`` metadata, and the defect histogram.
+
+        ``meshes`` gives pool i (numbered model by model, in sorted model
+        order) its own device mesh (launch.mesh.make_fleet_mesh): the
+        engine keeps its slot state AND its weights there, so pools on
+        different chips never share one. None leaves every pool on the
+        default device.
         """
         from repro.obs.flight import FlightRecorder
 
+        n_pools = len(models) * pools_per_model
+        if meshes is not None and len(meshes) != n_pools:
+            raise ValueError(f"got {len(meshes)} meshes for {n_pools} "
+                             "pools")
         obs = obs if obs is not None else Observability()
         registry = ModelRegistry()
         preview = engine_kw.pop("preview", True)
@@ -687,6 +703,7 @@ class GatewayCore:
                 eng = ContinuousBatchingEngine(
                     schedule, eps_apply, sample_shape, slots,
                     eps_params=models[name], preview=preview,
+                    mesh=None if meshes is None else meshes[pid],
                     pool_id=pid, obs=obs.child(), probes=probes,
                     flight=flight, **engine_kw)
                 pools.append(SlotPool(pid, eng, model=name))

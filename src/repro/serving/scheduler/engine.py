@@ -140,7 +140,10 @@ class ContinuousBatchingEngine:
         fuses when the eps model carries a VMEM-fitting ``mega_spec`` bound
         to this engine's exact (slots, *sample_shape) geometry and the
         engine is deterministic, history-free, and preview-free; True
-        raises if any of those fail, False forces the unfused tick.
+        raises if any of those fail, False forces the unfused tick. A
+        compiled (non-interpret) tick never fuses: the TPU compiler
+        refuses the megakernel (megastep.TPU_REFUSAL), so None resolves
+        to False there and True raises.
       plan_bank: a ``repro.autoplan.PlanBank`` searched on this engine's
         noise schedule (digest-validated).  Requests submitted with
         ``auto_plan=True`` get their SamplerPlan chosen AT ADMISSION:
@@ -247,7 +250,7 @@ class ContinuousBatchingEngine:
 
         self.mesh = mesh
         self.pool_id = pool_id
-        self.eps_params = eps_params
+        self.eps_params = self._place_params(eps_params)
         self.use_mega = self._resolve_mega(use_mega)
         self.tick_variant = ("mega" if self.use_mega else
                              "multistep" if self.max_order > 1 else "rows")
@@ -464,12 +467,28 @@ class ContinuousBatchingEngine:
         else:
             ok, why = mega_ops.eligible(
                 spec, jax.ShapeDtypeStruct((self.slots,) + self.shape,
-                                           self.dtype))
+                                           self.dtype),
+                interpret=self.interpret)
         if ok:
             return True
         if use_mega:                       # explicitly requested: loud
             raise ValueError(f"use_mega=True but {why}")
         return False
+
+    def _place_params(self, params):
+        """Put eps weights on this pool's mesh, replicated over it, so
+        the tick reads them from the pool's own devices (None stays
+        None; off-mesh the arrays stay where the caller put them)."""
+        if params is None or self.mesh is None:
+            return params
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return jax.device_put(params, NamedSharding(self.mesh, P()))
+
+    def devices(self) -> set:
+        """Every device holding this pool's slot state or weights."""
+        arrays = [self._x2] + jax.tree.leaves(self.eps_params)
+        return set().union(*(a.devices() for a in arrays
+                             if isinstance(a, jax.Array)))
 
     def _constrain(self, arr2):
         """Pin an (R, C)-shaped tick output to the slot-state sharding.
@@ -534,7 +553,7 @@ class ContinuousBatchingEngine:
                     f"{jnp.shape(n)}/{jnp.result_type(n)}, resident is "
                     f"{jnp.shape(o)}/{jnp.result_type(o)} — a swap must "
                     "preserve shapes/dtypes to reuse the compiled tick")
-        self.eps_params = new_params
+        self.eps_params = self._place_params(new_params)
         self._c_installs.inc()
 
     def _make_tick(self):
@@ -743,12 +762,6 @@ class ContinuousBatchingEngine:
                 f"request {req.request_id}: plan clip_x0={plan.clip_x0} != "
                 f"engine clip_x0={self.clip_x0} (the clip is a compile-time "
                 "slot-pool property)")
-        if plan.order > self.max_order:
-            raise RequestError(
-                RejectCode.ORDER_UNSUPPORTED,
-                f"request {req.request_id}: plan order={plan.order} exceeds "
-                f"engine max_order={self.max_order} (build the engine with "
-                "max_order >= the largest solver order it must serve)")
 
     def validate_request(self, req: SampleRequest) -> None:
         """Raise if this engine can never serve ``req`` (capability check).
@@ -770,6 +783,11 @@ class ContinuousBatchingEngine:
                     f"request {req.request_id}: auto_plan=True and an "
                     "explicit plan are mutually exclusive (the engine "
                     "fills plan in at admission)")
+            if req.solver_order != 1:
+                raise RequestError(
+                    RejectCode.AUTO_PLAN_CONFLICT,
+                    f"request {req.request_id}: auto_plan=True picks the "
+                    "solver order with the plan; order must stay 1")
             if self.plan_bank is None:
                 raise RequestError(
                     RejectCode.NO_PLAN_BANK,
@@ -790,11 +808,23 @@ class ContinuousBatchingEngine:
                     "0 somewhere) needs a stochastic=True engine "
                     "(deterministic tick has no PRNG)")
             self._validate_plan(req)
+            if req.order > self.max_order:
+                raise RequestError(
+                    RejectCode.ORDER_UNSUPPORTED,
+                    f"request {req.request_id}: solver order={req.order} "
+                    f"exceeds engine max_order={self.max_order} (build the "
+                    "engine with max_order >= the largest solver order it "
+                    "must serve)")
             if not 1 <= req.steps <= self.schedule.T:
                 raise RequestError(
                     RejectCode.BAD_STEPS,
                     f"request {req.request_id}: S={req.steps} "
                     f"outside [1, T={self.schedule.T}]")
+            if req.plan is None and req.order > 1 and req.stochastic:
+                raise RequestError(
+                    RejectCode.BAD_REQUEST,
+                    f"request {req.request_id}: multistep (order > 1) "
+                    "plans are deterministic — use eta = 0 or order = 1")
 
     def submit(self, req: SampleRequest,
                now: Optional[float] = None) -> bool:

@@ -7,8 +7,8 @@ quadratic / explicit-learned), any sigma schedule (scalar eta, per-step
 eta, explicit sigmas), and any solver order the engine was built for —
 the scheduler multiplexes arbitrary mixes of these through one resident
 slot batch with zero retraces. The legacy scalar knobs (S, eta, tau_kind,
-sigma_hat) remain as a convenience and compile to the equivalent plan at
-admission.
+sigma_hat, order) remain as a convenience and compile to the equivalent
+plan at admission.
 
 Timestamps are in the CALLER's clock (whatever ``now`` the engine is driven
 with — wall time by default, a virtual clock in trace-replay benchmarks).
@@ -59,6 +59,8 @@ class SampleRequest:
     eta: float = 0.0                   # 0 = DDIM, 1 = DDPM (Eq. 16)
     tau_kind: str = "linear"           # per-request sub-sequence spacing
     sigma_hat: bool = False            # over-dispersed DDPM variant
+    solver_order: int = 1              # Adams–Bashforth solver order of
+    #                                     the scalar-knob plan (eta = 0)
     plan: Optional[SamplerPlan] = None  # full per-request trajectory plan;
     #                                     overrides the scalar knobs above
     auto_plan: bool = False            # let the engine pick the plan from
@@ -108,7 +110,9 @@ class SampleRequest:
 
     @property
     def order(self) -> int:
-        return self.plan.order if self.plan is not None else 1
+        """The solver order actually executed (plan-aware)."""
+        return (self.plan.order if self.plan is not None
+                else self.solver_order)
 
     @property
     def eta_label(self) -> float:
@@ -132,7 +136,8 @@ class SampleRequest:
         """The plan this request executes on the given engine schedule."""
         if self.plan is not None:
             return self.plan
-        return self.sampler_config(clip_x0).to_plan(schedule)
+        return self.sampler_config(clip_x0).to_plan(schedule,
+                                                    order=self.solver_order)
 
 
 @dataclasses.dataclass

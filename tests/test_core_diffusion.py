@@ -3,7 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (NoiseSchedule, make_schedule, make_tau, q_sample,
                         predict_x0, eps_from_x0, posterior_sigma, sigma_hat,

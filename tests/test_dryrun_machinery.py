@@ -80,10 +80,22 @@ def test_roofline_terms_positive_and_bottleneck(mesh):
     lowered, cfg = _lower_smoke_train("smollm-135m", mesh)
     compiled = lowered.compile()
     terms = analyze(compiled, compiled.as_text(), n_chips=1,
+                    device_kind="TPU v5 lite",
                     model_flops=lm_model_flops(10_000_000, 2 * 16))
     assert terms.compute_s > 0 and terms.memory_s > 0
     assert terms.bottleneck in ("compute", "memory", "collective")
     assert 0 < terms.useful_ratio
+
+
+def test_peaks_keyed_by_device_kind_unknown_kind_raises():
+    """The roofline reads peaks from one table keyed by device_kind; a
+    device that is not in it is an error, never a default."""
+    from repro.launch.mesh import PEAKS, peaks
+    assert peaks("TPU v5 lite")["flops_bf16"] == 197e12
+    assert peaks("TPU v5 lite")["hbm_bw"] == 819e9
+    assert set(PEAKS) == {"TPU v5 lite"}
+    with pytest.raises(KeyError, match="cpu"):
+        peaks("cpu")
 
 
 def test_input_specs_all_combos_shapes():

@@ -9,6 +9,7 @@ import asyncio
 import threading
 import time
 
+import jax
 import numpy as np
 import pytest
 
@@ -17,9 +18,10 @@ from repro.obs import ListSink, Observability
 from repro.obs.schema import GATEWAY_STATS_KEYS
 from repro.serving.errors import RejectCode, RequestError
 from repro.serving.fleet import make_trunk_params, trunk_apply
-from repro.serving.gateway import (EngineBridge, GatewayCore, HAVE_HTTP,
+from repro.serving.gateway import (EngineBridge, GatewayCore,
                                    ModelRegistry, OverloadPolicy,
                                    parse_spec)
+from repro.sampling import SamplerPlan
 from repro.serving.scheduler.request import SampleRequest
 
 SCH = make_schedule("linear", T=100)
@@ -73,6 +75,48 @@ def test_parse_spec_deadline_relative_to_now():
     req = parse_spec({"S": 4, "deadline_s": 2.5}, 7, now=10.0)
     assert req.request_id == 7 and req.deadline == 12.5
     assert parse_spec({"S": 4}, 0, now=10.0).deadline is None
+
+
+def test_parse_spec_order_field():
+    assert parse_spec({"S": 4, "order": 2}, 0, now=0.0).order == 2
+    assert parse_spec({"S": 4}, 0, now=0.0).order == 1
+    with pytest.raises(RequestError, match="order must be"):
+        parse_spec({"order": 0}, 0, now=0.0)
+    with pytest.raises(RequestError, match="field 'order'"):
+        parse_spec({"order": 2.0}, 0, now=0.0)
+
+
+def test_order_field_serves_the_multistep_plan():
+    """``order`` in a request body selects the Adams-Bashforth plan of
+    that order (eta = 0); its result matches the plan's jnp reference."""
+    core = _gateway(max_order=2)
+    events = _serve_one(core, {"S": 6, "order": 2, "seed": 3})
+    assert events[-1]["event"] == "result"
+    x_T = jax.random.normal(jax.random.PRNGKey(3), (1, DIM))
+    want = SamplerPlan.build(SCH, tau=6, order=2).run(
+        lambda x, t: trunk_apply(PARAMS_A, x, t), x_T, backend="jnp")
+    # the engine tick and the jnp scan are two compiled programs that
+    # round the update differently: a few float32 ulp apart
+    np.testing.assert_allclose(events[-1]["x0"], np.asarray(want)[0],
+                               rtol=1e-5, atol=1e-6)
+    order1 = _serve_one(core, {"S": 6, "seed": 3})[-1]["x0"]
+    assert np.abs(order1 - events[-1]["x0"]).max() > 1e-3
+
+
+def test_order_beyond_pools_or_with_noise_is_typed_400():
+    core = _gateway(max_order=2, stochastic=True)
+    with pytest.raises(RequestError) as ei:
+        core.submit({"S": 6, "order": 3}, lambda e: None)
+    assert ei.value.code is RejectCode.ORDER_UNSUPPORTED
+    assert ei.value.status == 400
+    with pytest.raises(RequestError, match="deterministic") as ei:
+        core.submit({"S": 6, "order": 2, "eta": 1.0}, lambda e: None)
+    assert ei.value.code is RejectCode.BAD_REQUEST
+    with pytest.raises(RequestError, match="auto_plan") as ei:
+        core.submit({"S": 6, "order": 2, "auto_plan": True}, lambda e: None)
+    assert ei.value.code is RejectCode.AUTO_PLAN_CONFLICT
+    assert ei.value.status == 400
+    assert core.stats()["rejected"] == 3
 
 
 # --------------------------------------------------------- OverloadPolicy
@@ -344,11 +388,6 @@ def test_bridge_pump_failure_poisons_future_calls():
 
 
 # ------------------------------------------------------------ HTTP / SSE
-needs_http = pytest.mark.skipif(not HAVE_HTTP,
-                                reason="aiohttp not installed")
-
-
-@needs_http
 def test_http_sse_end_to_end_with_rollout():
     """One live server: JSON + SSE sampling across both models, typed
     HTTP errors, metrics/stats/health, and a rollout driven entirely

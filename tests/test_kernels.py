@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import SamplerConfig, make_schedule, sample, ddim_sample
 from repro.kernels import (fused_ddim_step, gqa_flash, mha_flash,

@@ -195,6 +195,8 @@ def test_eligibility_rule():
     assert not ok and "geometry" in why
     ok, why = megastep.eligible(eps.mega_spec, xT, budget=1024)
     assert not ok and "VMEM" in why
+    ok, why = megastep.eligible(eps.mega_spec, xT, interpret=False)
+    assert not ok and why == megastep.TPU_REFUSAL
     assert eps.mega_spec.vmem_bytes() > eps.mega_spec.weight_bytes() > 0
 
 
@@ -338,6 +340,21 @@ def test_engine_use_mega_validation():
     bare.slot_tile_aware = True
     eng2 = ContinuousBatchingEngine(SCH, bare, shape, slots=slots)
     assert not eng2.use_mega
+
+
+def test_compiled_engine_never_fuses():
+    """Compiled for the chip (interpret=False) the TPU compiler refuses the
+    megakernel: auto mode declines it and use_mega=True is a loud error."""
+    cfg, params = _tiny_dlm()
+    slots, seq = 2, 64
+    shape = (seq, cfg.latent_dim)
+    eps = dlm.make_tile_eps_fn(params, cfg, slots, seq)
+    eng = ContinuousBatchingEngine(SCH, eps, shape, slots=slots,
+                                   interpret=False)
+    assert not eng.use_mega and eng.tick_variant == "rows"
+    with pytest.raises(ValueError, match="TPU compiler refuses"):
+        ContinuousBatchingEngine(SCH, eps, shape, slots=slots,
+                                 interpret=False, use_mega=True)
 
 
 # ----------------------------------------------- metadata + small fixes

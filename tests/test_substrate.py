@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.data import GaussianMixture2D, SyntheticImages, SyntheticTokens
 from repro.sharding import (batch_spec, data_axes, shard_params,
