@@ -1,0 +1,7 @@
+"""Median request latency: scheduled send to final answer, over every
+request due in the window (a failed one counts as infinitely late)."""
+import measure
+
+
+def read(run):
+    return measure.percentile(measure.latencies(run), 50)
