@@ -26,7 +26,7 @@ slots refill mid-flight, and per-request latency is reported alongside
 engine occupancy/throughput stats (docs/serving.md). Telemetry flags
 (docs/observability.md): ``--dash`` live per-pool dashboard,
 ``--trace-out`` per-request JSONL spans, ``--prom-out`` Prometheus
-snapshot, ``--profile`` jax.profiler tick annotations; every replay ends
+snapshot, ``--profile`` jax.profiler host spans; every replay ends
 with a p50/p95/p99 latency + miss/drop summary table.
 """
 from __future__ import annotations
@@ -580,9 +580,13 @@ def main():
                          "(implies an in-memory ring even when faults "
                          "never fire; requires --probes)")
     ap.add_argument("--profile", action="store_true",
-                    help="with --scheduler: wrap ticks in jax.profiler "
-                    "trace annotations (repro/tick/<variant>) so a "
-                    "device profile attributes time per tick variant")
+                    help="jax.profiler host spans on every engine tick and "
+                    "its phases (repro/pool<i>/{tick,admit,states,launch,"
+                    "block,previews,retire,checkpoint}, repro/engine/... "
+                    "outside a fleet), the fleet dispatch, gateway pump, "
+                    "bridge commands and HTTP encode, so a device profile "
+                    "puts idle time down to host work; off, a span costs "
+                    "one call and one branch (docs/observability.md)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()

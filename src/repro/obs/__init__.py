@@ -12,17 +12,18 @@ scripts/lint_serving.py, which forbids JAX anywhere else in obs/).
 Entry point is :class:`Observability`: pass one to
 ``ContinuousBatchingEngine`` / ``PoolFleet.build`` and the engine's
 ``stats()`` becomes a view over real instruments, ``add_sink`` turns on
-per-request JSONL spans, and ``profile=True`` wraps tick variants in
-``jax.profiler`` annotations. For in-flight numerics, build the engine
-with ``probes=`` (a :class:`ProbeSpec`) and optionally attach a
-:class:`FlightRecorder` for postmortem dumps.
+per-request JSONL spans, and ``profile=True`` turns ``span(name)`` into
+``jax.profiler`` annotations (obs/profiling.py names them). For
+in-flight numerics, build the engine with ``probes=`` (a
+:class:`ProbeSpec`) and optionally attach a :class:`FlightRecorder` for
+postmortem dumps.
 """
 from .core import Observability
 from .dashboard import render_dashboard, render_summary, summarize_results
 from .flight import (FlightRecorder, attribute_nonfinite,
                      detect_weight_corruption, read_flight)
 from .probes import ProbeSpec
-from .profiling import annotate, format_hbm_table, modeled_hbm_table
+from .profiling import NO_SPAN, annotate
 from .registry import (Counter, Gauge, Histogram, LATENCY_BUCKETS_S,
                        MetricsRegistry, SLACK_BUCKETS_S, render_prometheus)
 from .schema import (ENGINE_STATS_KEYS, FLEET_STATS_KEYS, POOL_STATS_KEYS,
@@ -39,7 +40,7 @@ __all__ = [
     "Tracer", "TraceContext", "JsonlSink", "ListSink", "EVENT_KINDS",
     "plan_digest", "read_jsonl", "spans", "check_spans", "ordering",
     # profiling plane
-    "annotate", "modeled_hbm_table", "format_hbm_table",
+    "annotate", "NO_SPAN",
     # device-probe + flight-recorder tier
     "ProbeSpec", "PROBE_COLUMNS", "FlightRecorder",
     "attribute_nonfinite", "detect_weight_corruption", "read_flight",

@@ -8,9 +8,10 @@ One :class:`Observability` bundles the three telemetry planes:
 * ``tracer`` — the span plane (obs/trace.py). Inert until a sink is
   attached (``add_sink``); every event emission is gated on
   ``tracer.sinks`` so un-traced serving pays ~nothing.
-* ``profile`` — the profiler plane: when True the engine wraps its tick
-  variants in ``jax.profiler`` trace annotations (obs/profiling.py) so a
-  real device profile attributes time to ``repro/tick/<variant>``.
+* ``profile`` — the profiler plane: when True, ``span(name)`` is a
+  ``jax.profiler`` trace annotation (obs/profiling.py), so a device
+  profile puts idle time down to the engine, supervisor, fleet, bridge
+  and HTTP spans; when False it is one shared no-op.
 
 Topology: each engine owns a PRIVATE registry (instruments never need a
 pool label — identity attaches at render time), while a fleet shares ONE
@@ -23,6 +24,7 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Sequence, Tuple
 
+from .profiling import NO_SPAN, annotate
 from .registry import MetricsRegistry, render_prometheus as _render
 from .trace import TraceContext, Tracer
 
@@ -36,6 +38,12 @@ class Observability:
             MetricsRegistry()
         self.tracer = tracer if tracer is not None else Tracer()
         self.profile = bool(profile)
+
+    # ----------------------------------------------------------- profiling
+    def span(self, name: str):
+        """A host span named ``name`` on the profiler's clock when
+        ``profile`` is on; the shared no-op context when it is off."""
+        return annotate(name) if self.profile else NO_SPAN
 
     # ------------------------------------------------------------- tracing
     @property

@@ -37,6 +37,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 from repro.core.schedules import NoiseSchedule
 from repro.obs import Observability
+from repro.obs.profiling import FLEET_DISPATCH
 from repro.obs.registry import render_prometheus as _render_prom
 from repro.serving.errors import RejectCode, RequestError
 from repro.serving.scheduler import ContinuousBatchingEngine
@@ -212,38 +213,39 @@ class PoolFleet:
         this tier — the destination pool fills the plan at its own
         admission with its own tick EWMA.
         """
-        results: List[SampleResult] = []
-        deferred: List[SampleRequest] = []
-        while len(self.queue) and any(p.capacity > 0 for p in self.pools):
-            req, missed = self.queue.pop(now)
-            for m in missed:
-                self._c_dropped.inc()
-                if m.trace is not None:
-                    m.trace.emit("drop", now, reason="expired")
-                results.append(SampleResult.drop(m, now))
-            if req is None:
-                break
-            pool, why = pick_pool(self.pools, req, explain=True)
-            if pool is None:
-                # no ELIGIBLE pool has capacity (raced out, or every pool
-                # serving this request's model is busy/draining). Set the
-                # request aside and keep popping: one model's backlog must
-                # not head-of-line-block another model's dispatchable work
-                # behind it in the global EDF order. Per model the EDF
-                # order is preserved — capacity only shrinks within one
-                # dispatch round, so later same-model pops defer too.
-                deferred.append(req)
-                continue
-            self.obs.registry.counter(
-                "fleet_routed_total", "dispatches by routing decision",
-                reason=why).inc()
-            if req.trace is not None:
-                req.trace.pool_id = pool.pool_id
-                req.trace.emit("route", now, reason=why)
-            pool.dispatch(req, now)
-        for req in deferred:      # back into the global queue, stamps kept
-            self.queue.requeue(req, now)
-        return results
+        with self.obs.span(FLEET_DISPATCH):
+            results: List[SampleResult] = []
+            deferred: List[SampleRequest] = []
+            while len(self.queue) and any(p.capacity > 0 for p in self.pools):
+                req, missed = self.queue.pop(now)
+                for m in missed:
+                    self._c_dropped.inc()
+                    if m.trace is not None:
+                        m.trace.emit("drop", now, reason="expired")
+                    results.append(SampleResult.drop(m, now))
+                if req is None:
+                    break
+                pool, why = pick_pool(self.pools, req, explain=True)
+                if pool is None:
+                    # no ELIGIBLE pool has capacity (raced out, or every pool
+                    # serving this request's model is busy/draining). Set the
+                    # request aside and keep popping: one model's backlog must
+                    # not head-of-line-block another model's dispatchable work
+                    # behind it in the global EDF order. Per model the EDF
+                    # order is preserved — capacity only shrinks within one
+                    # dispatch round, so later same-model pops defer too.
+                    deferred.append(req)
+                    continue
+                self.obs.registry.counter(
+                    "fleet_routed_total", "dispatches by routing decision",
+                    reason=why).inc()
+                if req.trace is not None:
+                    req.trace.pool_id = pool.pool_id
+                    req.trace.emit("route", now, reason=why)
+                pool.dispatch(req, now)
+            for req in deferred:      # back into the global queue, stamps kept
+                self.queue.requeue(req, now)
+            return results
 
     # --------------------------------------------------------------- loop
     @property

@@ -30,6 +30,8 @@ import queue
 import threading
 from typing import Optional
 
+from repro.obs.profiling import BRIDGE_COMMANDS
+
 from .core import GatewayCore
 
 
@@ -75,18 +77,21 @@ class EngineBridge:
 
     # ------------------------------------------------------------ the loop
     def _drain_commands(self, first=None) -> None:
+        if first is None:
+            return
         cmd = first
-        while cmd is not None:
-            fn, args, kwargs, fut = cmd
-            if fut.set_running_or_notify_cancel():
+        with self.core.obs.span(BRIDGE_COMMANDS):
+            while cmd is not None:
+                fn, args, kwargs, fut = cmd
+                if fut.set_running_or_notify_cancel():
+                    try:
+                        fut.set_result(fn(*args, **kwargs))
+                    except BaseException as e:  # typed RequestErrors too
+                        fut.set_exception(e)
                 try:
-                    fut.set_result(fn(*args, **kwargs))
-                except BaseException as e:  # typed RequestErrors included
-                    fut.set_exception(e)
-            try:
-                cmd = self._cmds.get_nowait()
-            except queue.Empty:
-                cmd = None
+                    cmd = self._cmds.get_nowait()
+                except queue.Empty:
+                    cmd = None
 
     def _run(self) -> None:
         while not self._stop.is_set():

@@ -44,6 +44,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from repro.obs import Observability
+from repro.obs.profiling import GATEWAY_DELIVER, GATEWAY_PUMP
 from repro.obs.registry import render_prometheus as _render_prom
 from repro.serving.errors import RejectCode, RequestError
 from repro.serving.fleet import PoolFleet, PoolState, SlotPool
@@ -399,12 +400,22 @@ class GatewayCore:
         also fires preview callbacks), then terminal delivery, then the
         swap state machine (drained pools observed after their tick).
         """
-        wall = now is None
-        t = time.perf_counter() if wall else now
-        delivered = self._shed(t)
-        results = (self.supervisor.tick(now)
-                   if self.supervisor is not None
-                   else self.fleet.tick(now))
+        with self.obs.span(GATEWAY_PUMP):
+            wall = now is None
+            t = time.perf_counter() if wall else now
+            delivered = self._shed(t)
+            results = (self.supervisor.tick(now)
+                       if self.supervisor is not None
+                       else self.fleet.tick(now))
+            with self.obs.span(GATEWAY_DELIVER):
+                delivered += self._deliver(results)
+            self._advance_swap(time.perf_counter() if wall else now)
+            return delivered
+
+    def _deliver(self, results) -> int:
+        """Terminal events for this round's results (the finite check and
+        the clients' callbacks); returns how many fired."""
+        delivered = 0
         for r in results:
             if r.request_id not in self._handlers:
                 continue            # warm-up / foreign traffic
@@ -460,7 +471,6 @@ class GatewayCore:
                         self._h_defect.observe(d)
                 self._terminal(r.request_id, event)
             delivered += 1
-        self._advance_swap(time.perf_counter() if wall else now)
         return delivered
 
     def run_until_idle(self, max_pumps: Optional[int] = None,

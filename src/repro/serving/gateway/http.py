@@ -43,6 +43,7 @@ from typing import Dict, Optional, Tuple
 import numpy as np
 from aiohttp import web
 
+from repro.obs.profiling import HTTP_ENCODE
 from repro.serving.errors import RequestError
 
 from .bridge import EngineBridge
@@ -78,6 +79,9 @@ def _error_response(err: RequestError) -> "web.Response":
 
 def build_app(bridge: EngineBridge) -> "web.Application":
     core = bridge.core
+    # a profiler span around each body built on this event loop's thread
+    # (``_wire`` and its JSON or SSE text); a no-op with profiling off
+    span = core.obs.span
 
     def _cancel(rid: int) -> None:
         """Best-effort cancellation from transport-level teardown (the
@@ -125,7 +129,9 @@ def build_app(bridge: EngineBridge) -> "web.Application":
                     {"error": ev["code"], "message": ev["message"],
                      "request_id": rid}, status=ev["status"],
                     headers=_retry_headers(ev.get("retry_after_s")))
-            return web.json_response(_wire(ev))
+            with span(HTTP_ENCODE):
+                body = json.dumps(_wire(ev))
+            return web.json_response(text=body)
 
         resp = web.StreamResponse(
             headers={"Content-Type": "text/event-stream",
@@ -136,7 +142,9 @@ def build_app(bridge: EngineBridge) -> "web.Application":
             await resp.write(_sse("accepted", {"request_id": rid}))
             while True:
                 ev = await events.get()
-                await resp.write(_sse(ev["event"], _wire(ev)))
+                with span(HTTP_ENCODE):
+                    chunk = _sse(ev["event"], _wire(ev))
+                await resp.write(chunk)
                 if ev["event"] in ("result", "error"):
                     break
             await resp.write_eof()

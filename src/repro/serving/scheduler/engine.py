@@ -38,7 +38,6 @@ per request plus engine-level throughput/occupancy stats.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 import time
@@ -51,7 +50,7 @@ import numpy as np
 from repro.core import NoiseSchedule, StepStates
 from repro.core.sampler import slot_tile_step
 from repro.obs import Observability
-from repro.obs.profiling import annotate
+from repro.obs.profiling import engine_span_names
 from repro.obs.registry import SLACK_BUCKETS_S
 from repro.obs.trace import plan_digest as _plan_digest
 from repro.sampling import MAX_ORDER, SamplerPlan
@@ -175,9 +174,11 @@ class ContinuousBatchingEngine:
         ``stats()`` dict is a thin view over them, so callers see the
         same numbers either way); attaching a trace sink turns on
         per-request span events (submit/admit/first_tick/preview/retire/
-        drop) through the request's TraceContext; ``profile=True`` wraps
-        the tick in a ``jax.profiler`` trace annotation named
-        ``repro/tick/<variant>``. All telemetry is host-side by contract
+        drop) through the request's TraceContext; ``profile=True`` puts
+        ``jax.profiler`` spans ``repro/pool<i>/{tick, admit, states,
+        launch, block, previews, retire, checkpoint}`` (``repro/engine/``
+        when ``pool_id`` is None) around the tick and its phases
+        (obs/profiling.py). All telemetry is host-side by contract
         — no JAX op is ever added to the tick program, so the
         one-compiled-tick and bit-identity guarantees are unaffected
         (tests/test_obs.py). None builds a private, sink-less handle:
@@ -435,6 +436,16 @@ class ContinuousBatchingEngine:
     @property
     def weight_installs(self) -> int:
         return int(self._c_installs.value)
+
+    @property
+    def pool_id(self) -> Optional[int]:
+        return self._pool_id
+
+    @pool_id.setter
+    def pool_id(self, pool_id: Optional[int]) -> None:
+        # span names follow the pool's identity; built here, not per tick
+        self._pool_id = pool_id
+        self._span = engine_span_names(pool_id)
 
     @property
     def _tick_wall_s(self) -> float:
@@ -921,98 +932,102 @@ class ContinuousBatchingEngine:
                 ctx.emit("select", now, outcome=self._last_outcome)
 
     def _admit(self, now: float, results: List[SampleResult]) -> None:
-        while self._free and len(self.queue):
-            req, missed = self.queue.pop(now, select=self._fill_auto_plan)
-            results.extend(self._drop(m, now, reason="expired")
-                           for m in missed)
-            if req is None:
-                break
-            headroom = (req.deadline - now if req.deadline is not None
-                        else None)
-            b = self._free.pop()
-            ck = req.resume
-            slot = _Slot(req=req, table=self._table_for(req), k=0,
-                         admit_t=now, headroom_s=headroom)
-            self._slots[b] = slot
-            if ck is None:
-                self._x2 = self._write_fn(self._x2, self._xT_fn(req.seed),
-                                          b * self._rps)
-            else:
-                # mid-trajectory restore: refill the slot's tile rows from
-                # the checkpoint and continue from step k — same tables,
-                # same compiled tick, so the remaining steps are the exact
-                # computation the uninterrupted run would have done
-                req.resume = None
-                if not 0 <= ck.k < req.steps:
-                    raise ValueError(
-                        f"request {req.request_id}: checkpoint k={ck.k} "
-                        f"outside [0, {req.steps})")
-                self.write_slot_rows(b, ck.x_rows, ck.hist_rows)
-                slot.k = int(ck.k)
-                slot.previews = int(ck.previews)
-                self._c_resumed.inc()
-            wait = (now - req.submit_t if req.submit_t is not None else 0.0)
-            self._h_wait.observe(wait)
-            ctx = req.trace
-            if ctx is not None:
-                if self.pool_id is not None:
-                    ctx.pool_id = self.pool_id
-                if ctx.nfe is None:
-                    ctx.nfe = req.steps
-                if ctx.plan_digest is None:
-                    ctx.plan_digest = _plan_digest(
-                        req.resolved_plan(self.schedule, self.clip_x0))
-                ctx.emit("admit", now, slot=b, wait_s=wait,
-                         headroom_s=headroom)
-                if ck is not None:
-                    ctx.emit("resume", now, k=int(ck.k),
-                             from_pool=ck.pool_id)
+        with self.obs.span(self._span["admit"]):
+            while self._free and len(self.queue):
+                req, missed = self.queue.pop(now, select=self._fill_auto_plan)
+                results.extend(self._drop(m, now, reason="expired")
+                               for m in missed)
+                if req is None:
+                    break
+                headroom = (req.deadline - now if req.deadline is not None
+                            else None)
+                b = self._free.pop()
+                ck = req.resume
+                slot = _Slot(req=req, table=self._table_for(req), k=0,
+                             admit_t=now, headroom_s=headroom)
+                self._slots[b] = slot
+                if ck is None:
+                    self._x2 = self._write_fn(self._x2, self._xT_fn(req.seed),
+                                              b * self._rps)
+                else:
+                    # mid-trajectory restore: refill the slot's tile rows from
+                    # the checkpoint and continue from step k — same tables,
+                    # same compiled tick, so the remaining steps are the exact
+                    # computation the uninterrupted run would have done
+                    req.resume = None
+                    if not 0 <= ck.k < req.steps:
+                        raise ValueError(
+                            f"request {req.request_id}: checkpoint k={ck.k} "
+                            f"outside [0, {req.steps})")
+                    self.write_slot_rows(b, ck.x_rows, ck.hist_rows)
+                    slot.k = int(ck.k)
+                    slot.previews = int(ck.previews)
+                    self._c_resumed.inc()
+                wait = (now - req.submit_t if req.submit_t is not None
+                        else 0.0)
+                self._h_wait.observe(wait)
+                ctx = req.trace
+                if ctx is not None:
+                    if self.pool_id is not None:
+                        ctx.pool_id = self.pool_id
+                    if ctx.nfe is None:
+                        ctx.nfe = req.steps
+                    if ctx.plan_digest is None:
+                        ctx.plan_digest = _plan_digest(
+                            req.resolved_plan(self.schedule, self.clip_x0))
+                    ctx.emit("admit", now, slot=b, wait_s=wait,
+                             headroom_s=headroom)
+                    if ck is not None:
+                        ctx.emit("resume", now, k=int(ck.k),
+                                 from_pool=ck.pool_id)
 
     def _states(self) -> StepStates:
-        B = self.slots
-        t = np.full((B,), self._idle_row["t"], np.int32)
-        cols = {k: np.full((B,), v, np.float32)
-                for k, v in self._idle_row.items() if k != "t"}
-        seeds = np.zeros((B,), np.uint32)
-        ks = np.zeros((B,), np.uint32)
-        solver_w = None
-        if self.max_order > 1:
-            solver_w = np.zeros((B, self.max_order), np.float32)
-            solver_w[:, 0] = 1.0       # idle slots: identity combine
-        for b, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            tab, k = slot.table, slot.k
-            t[b] = tab["t"][k]
-            for name in cols:
-                cols[name][b] = tab[name][k]
-            seeds[b] = np.uint32(slot.req.seed & 0xFFFFFFFF)
-            ks[b] = np.uint32(k)
-            if solver_w is not None:
-                w = tab["solver_w"][k]         # (order,) — plan's own order
-                solver_w[b, :] = 0.0
-                solver_w[b, :len(w)] = w
-        seed = None
-        if self.stochastic:
-            # per-slot per-tick stream seed: full-avalanche mix of the
-            # request seed and the step index (placement-invariant)
-            seed = jnp.asarray(
-                _fmix32(seeds ^ (ks * _GOLDEN)).astype(np.int32))
-        return StepStates(t=jnp.asarray(t),
-                          c_x0=jnp.asarray(cols["c_x0"]),
-                          c_dir=jnp.asarray(cols["c_dir"]),
-                          c_noise=jnp.asarray(cols["c_noise"]),
-                          sqrt_a_t=jnp.asarray(cols["sqrt_a_t"]),
-                          sqrt_1m_a_t=jnp.asarray(cols["sqrt_1m_a_t"]),
-                          seed=seed,
-                          solver_w=(None if solver_w is None
-                                    else jnp.asarray(solver_w)))
+        with self.obs.span(self._span["states"]):
+            B = self.slots
+            t = np.full((B,), self._idle_row["t"], np.int32)
+            cols = {k: np.full((B,), v, np.float32)
+                    for k, v in self._idle_row.items() if k != "t"}
+            seeds = np.zeros((B,), np.uint32)
+            ks = np.zeros((B,), np.uint32)
+            solver_w = None
+            if self.max_order > 1:
+                solver_w = np.zeros((B, self.max_order), np.float32)
+                solver_w[:, 0] = 1.0       # idle slots: identity combine
+            for b, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                tab, k = slot.table, slot.k
+                t[b] = tab["t"][k]
+                for name in cols:
+                    cols[name][b] = tab[name][k]
+                seeds[b] = np.uint32(slot.req.seed & 0xFFFFFFFF)
+                ks[b] = np.uint32(k)
+                if solver_w is not None:
+                    w = tab["solver_w"][k]     # (order,): plan's own order
+                    solver_w[b, :] = 0.0
+                    solver_w[b, :len(w)] = w
+            seed = None
+            if self.stochastic:
+                # per-slot per-tick stream seed: full-avalanche mix of the
+                # request seed and the step index (placement-invariant)
+                seed = jnp.asarray(
+                    _fmix32(seeds ^ (ks * _GOLDEN)).astype(np.int32))
+            return StepStates(t=jnp.asarray(t),
+                              c_x0=jnp.asarray(cols["c_x0"]),
+                              c_dir=jnp.asarray(cols["c_dir"]),
+                              c_noise=jnp.asarray(cols["c_noise"]),
+                              sqrt_a_t=jnp.asarray(cols["sqrt_a_t"]),
+                              sqrt_1m_a_t=jnp.asarray(cols["sqrt_1m_a_t"]),
+                              seed=seed,
+                              solver_w=(None if solver_w is None
+                                        else jnp.asarray(solver_w)))
 
     def _read_slot(self, b: int) -> np.ndarray:
-        rows = self._x2[b * self._rps:(b + 1) * self._rps]
-        if self.dtype == jnp.bfloat16:   # numpy has no bf16
-            rows = rows.astype(jnp.float32)
-        return np.asarray(rows).ravel()[:self._n].reshape(self.shape)
+        with self.obs.span(self._span["retire"]):
+            rows = self._x2[b * self._rps:(b + 1) * self._rps]
+            if self.dtype == jnp.bfloat16:   # numpy has no bf16
+                rows = rows.astype(jnp.float32)
+            return np.asarray(rows).ravel()[:self._n].reshape(self.shape)
 
     # --------------------------------------- checkpoint / migrate / cancel
     @property
@@ -1065,8 +1080,9 @@ class ContinuousBatchingEngine:
     def snapshot_slots(self,
                        now: Optional[float] = None) -> List[SlotCheckpoint]:
         """Checkpoint every resident slot (the supervisor's sweep)."""
-        return [self.snapshot_slot(b, now) for b, s in
-                enumerate(self._slots) if s is not None]
+        with self.obs.span(self._span["checkpoint"]):
+            return [self.snapshot_slot(b, now) for b, s in
+                    enumerate(self._slots) if s is not None]
 
     def evict_residents(self) -> List[SampleRequest]:
         """Free every resident slot and hand back its request (no terminal
@@ -1104,19 +1120,22 @@ class ContinuousBatchingEngine:
         return bool(removed)
 
     def _deliver_previews(self, x0_2, now: float) -> None:
-        for b, slot in enumerate(self._slots):
-            if slot is None:
-                continue
-            req, done = slot.req, slot.k + 1
-            if (req.preview_every > 0 and req.on_preview is not None
-                    and done < req.steps and done % req.preview_every == 0):
-                rows = x0_2[b * self._rps:(b + 1) * self._rps]
-                x0 = np.asarray(rows).ravel()[:self._n].reshape(self.shape)
-                req.on_preview(req.request_id, done, x0)
-                slot.previews += 1
-                self._c_previews.inc()
-                if req.trace is not None:
-                    req.trace.emit("preview", now, k=done)
+        with self.obs.span(self._span["previews"]):
+            for b, slot in enumerate(self._slots):
+                if slot is None:
+                    continue
+                req, done = slot.req, slot.k + 1
+                if (req.preview_every > 0 and req.on_preview is not None
+                        and done < req.steps
+                        and done % req.preview_every == 0):
+                    rows = x0_2[b * self._rps:(b + 1) * self._rps]
+                    x0 = (np.asarray(rows).ravel()[:self._n]
+                          .reshape(self.shape))
+                    req.on_preview(req.request_id, done, x0)
+                    slot.previews += 1
+                    self._c_previews.inc()
+                    if req.trace is not None:
+                        req.trace.emit("preview", now, k=done)
 
     # -------------------------------------------------- device-probe host
     def _record_frame(self, vals: np.ndarray, now: float) -> None:
@@ -1196,6 +1215,10 @@ class ContinuousBatchingEngine:
         wall-clock mode (now=None) retirement re-stamps AFTER the step so
         finish_t/deadline checks include the compute that finished it.
         """
+        with self.obs.span(self._span["tick"]):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> List[SampleResult]:
         wall = now is None
         now = time.perf_counter() if wall else now
         results: List[SampleResult] = []
@@ -1207,8 +1230,7 @@ class ContinuousBatchingEngine:
         frame_dev = None
         probed = self.probes_on and self._tick_probed is not None
         t0 = time.perf_counter()
-        with (annotate(f"repro/tick/{self.tick_variant}")
-              if self.obs.profile else contextlib.nullcontext()):
+        with self.obs.span(self._span["launch"]):
             if probed:
                 p = (() if self.eps_params is None else (self.eps_params,))
                 if self.max_order == 1:
@@ -1233,6 +1255,7 @@ class ContinuousBatchingEngine:
                     else self._tick_fn(self._x2, self._hist2, states,
                                        self.eps_params))
             self._x2, x0_2 = out if self.preview else (out, None)
+        with self.obs.span(self._span["block"]):
             jax.block_until_ready(self._x2)
         t1 = time.perf_counter()
         self._c_wall.inc(t1 - t0)

@@ -17,7 +17,7 @@ Covers the acceptance criteria:
   * fleet-wide reset_stats: pool engines and fleet aggregates zero,
     warm-up state (compiled ticks, tick EWMA) survives;
   * bank-selection outcome counters + select events, dashboard /
-    summary rendering, and the modeled-HBM attribution table.
+    summary rendering, and the profiler span helper.
 """
 import json
 
@@ -29,9 +29,8 @@ from repro.autoplan import PlanBank
 from repro.core import make_schedule
 from repro.obs import (ENGINE_STATS_KEYS, FLEET_STATS_KEYS,
                        POOL_STATS_KEYS, Histogram, JsonlSink, ListSink,
-                       MetricsRegistry, Observability, annotate,
-                       check_spans, format_hbm_table, modeled_hbm_table,
-                       ordering, read_jsonl, render_dashboard,
+                       NO_SPAN, MetricsRegistry, Observability, annotate,
+                       check_spans, ordering, read_jsonl, render_dashboard,
                        render_prometheus, render_summary, spans,
                        summarize_results)
 from repro.sampling import SamplerPlan, TauSpec
@@ -474,15 +473,17 @@ def test_dashboard_renders_probe_quality_columns():
     assert "n/a" in render_dashboard(_engine().stats())
 
 
-def test_modeled_hbm_table_and_annotate():
-    eng = _engine()
-    rows = modeled_hbm_table(eng)
-    by_name = {r["component"]: r for r in rows}
-    assert {"state_read", "state_write", "total"} <= set(by_name)
-    assert by_name["state_read"]["bytes"] == by_name["state_write"]["bytes"]
-    known = sum(r["bytes"] for r in rows[:-1] if r["bytes"] is not None)
-    assert by_name["total"]["bytes"] == known
-    text = format_hbm_table(rows)
-    assert "state_read" in text and "total" in text
-    with annotate("repro/test/region"):     # profiler-off: plain no-op
+def test_span_helper_and_annotate():
+    from jax.profiler import TraceAnnotation
+    off = Observability()
+    # profile off: every name gets the one shared no-op context
+    assert off.span("repro/a") is off.span("repro/b") is NO_SPAN
+    with off.span("repro/a"), off.span("repro/a"):   # re-entrant
+        pass
+    on = Observability(profile=True)
+    assert isinstance(on.span("repro/a"), TraceAnnotation)
+    assert isinstance(on.child().span("repro/a"), TraceAnnotation)
+    with on.span("repro/test/region"):   # no capture running: a no-op
+        pass
+    with annotate("repro/test/region"):
         pass

@@ -16,8 +16,7 @@ Covers the probe contract and the flight-recorder forensics:
   * mega + probes is a loud ctor error (the fused kernel's eps never
     materializes), and use_mega=False + probes composes;
   * FlightRecorder ring/dump/read round-trip, NaN->null cleaning,
-    nonfinite attribution, and the silent-weight-corruption detector;
-  * modeled_hbm_table's probe rows (and the mega-variant rows).
+    nonfinite attribution, and the silent-weight-corruption detector.
 """
 import math
 
@@ -29,7 +28,7 @@ import pytest
 from repro.core import make_schedule
 from repro.obs import (PROBE_COLUMNS, FlightRecorder, ProbeSpec,
                        attribute_nonfinite, detect_weight_corruption,
-                       modeled_hbm_table, read_flight)
+                       read_flight)
 from repro.obs.schema import FLIGHT_FRAME_KEYS, FLIGHT_HEADER_KEYS
 from repro.serving.scheduler import ContinuousBatchingEngine, SampleRequest
 
@@ -297,38 +296,3 @@ def test_engine_dumps_frames_into_its_flight_ring(tmp_path):
     header, frames = read_flight(path)
     assert header["pool"] == 0
     assert all(set(f) == FLIGHT_FRAME_KEYS for f in frames)
-
-
-# --------------------------------------------------- modeled HBM table
-def test_modeled_hbm_probe_rows():
-    comps = lambda e: {r["component"] for r in modeled_hbm_table(e)}
-    plain = comps(_engine())
-    assert not plain & {"probe_frame", "probe_prev_eps"}
-    probed = comps(_engine(probes=True))
-    assert {"probe_frame", "probe_prev_eps"} <= probed
-    # multistep engines read the defect reference from the AB history
-    # already on device: no extra carry buffer to account
-    multi = comps(_engine(probes=True, max_order=2))
-    assert "probe_frame" in multi and "probe_prev_eps" not in multi
-
-
-def test_modeled_hbm_mega_variant_rows():
-    from repro import diffusion_lm as dlm
-    from repro.models.common import ArchConfig
-    arch = ArchConfig(name="hbm-mega-test", family="dense", n_layers=2,
-                      d_model=64, n_heads=2, n_kv_heads=2, d_ff=128,
-                      vocab=50)
-    cfg = dlm.DiffusionLMConfig(arch=arch, time_dim=32, latent_dim=32)
-    params = dlm.init_params(jax.random.PRNGKey(0), cfg)
-    slots, seq = 2, 64
-    eps = dlm.make_tile_eps_fn(params, cfg, slots, seq)
-    eng = ContinuousBatchingEngine(SCH, eps, (seq, cfg.latent_dim),
-                                   slots=slots)
-    assert eng.use_mega
-    rows = {r["component"]: r for r in modeled_hbm_table(eng)}
-    assert rows["trunk_weights"]["bytes"] is not None   # spec is visible
-    assert rows["eps_roundtrip"]["bytes"] == 0          # fused in-kernel
-    assert "probe_frame" not in rows
-    known = sum(r["bytes"] for c, r in rows.items()
-                if r["bytes"] is not None and c != "total")
-    assert rows["total"]["bytes"] == known
