@@ -11,7 +11,8 @@ pool is quarantined while the fleet keeps serving" (docs/resilience.md):
                             disabled injector is ``None`` and the guarded
                             path costs one host-side identity test
   checkpoint.CheckpointStore  latest per-request SlotCheckpoint (the
-                            engine's ``snapshot_slot`` output): DDIM's
+                            engine's ``snapshot_slots`` sweep, one
+                            device->host fetch a sweep): DDIM's
                             deterministic process makes a slot's
                             ``(x_t rows, k, eps-history)`` a complete
                             trajectory state, so migration is a refill,

@@ -178,7 +178,7 @@ class FaultInjector:
             residents = engine.resident_requests()
             if residents:
                 b, req = residents[0]
-                step = int(engine.snapshot_slot(b).k)
+                step = engine.resident_step(b)
                 rows = np.full(engine.slot_rows_shape, np.nan, np.float32)
                 engine.write_slot_rows(b, rows)
                 self.log.append(f)

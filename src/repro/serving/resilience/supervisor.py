@@ -25,7 +25,8 @@ What it adds around each pool's tick:
   the pool's router health score; each clean tick recovers it.
 * **checkpoint sweep** — every ``checkpoint_every`` busy ticks, every
   resident slot is snapshotted into the :class:`CheckpointStore`
-  (latest-wins); terminal results forget theirs.
+  (latest-wins) from one device->host fetch of the pool's state;
+  terminal results forget theirs.
 * **fault injection** — the optional :class:`FaultInjector` hooks run
   inside the guarded region, so injected faults exercise the identical
   code path an organic fault would. ``injector=None`` (the default)

@@ -305,6 +305,9 @@ class ContinuousBatchingEngine:
         self._c_resumed = reg.counter(
             "engine_resumed_total",
             "checkpointed trajectories resumed mid-flight")
+        self._c_ck_fetches = reg.counter(
+            "engine_checkpoint_fetches_total",
+            "device->host fetches of the slot state by checkpoint sweeps")
         self._c_wall = reg.counter(
             "engine_tick_wall_seconds",
             "accumulated wall time inside the jitted tick")
@@ -1040,6 +1043,14 @@ class ContinuousBatchingEngine:
         return [(b, s.req) for b, s in enumerate(self._slots)
                 if s is not None]
 
+    def resident_step(self, b: int) -> int:
+        """Next step index of resident slot ``b`` (a snapshot's ``k``),
+        read from the host record without touching the device."""
+        slot = self._slots[b]
+        if slot is None:
+            raise ValueError(f"slot {b} is not resident")
+        return slot.k
+
     def write_slot_rows(self, b: int, rows, hist_rows=None) -> None:
         """Overwrite slot ``b``'s tile rows (and optionally its
         eps-history rows) with host-provided values — the checkpoint
@@ -1059,30 +1070,45 @@ class ContinuousBatchingEngine:
             self._hist2 = self._hist_write_fn(self._hist2, h,
                                               b * self._rps)
 
+    def _checkpoint(self, b: int, x2, hist2,
+                    now: Optional[float]) -> SlotCheckpoint:
+        """Slot ``b``'s checkpoint, its rows copied out of ``x2`` /
+        ``hist2`` (the device state or a host copy of it): the copies own
+        only the slot's bytes, exact bits (bfloat16 via ml_dtypes)."""
+        slot = self._slots[b]
+        lo, hi = b * self._rps, (b + 1) * self._rps
+        return SlotCheckpoint(
+            request_id=slot.req.request_id, k=slot.k,
+            x_rows=np.array(x2[lo:hi]),
+            hist_rows=None if hist2 is None else np.array(hist2[:, lo:hi]),
+            previews=slot.previews, pool_id=self.pool_id, taken_t=now)
+
     def snapshot_slot(self, b: int,
                       now: Optional[float] = None) -> SlotCheckpoint:
         """Copy slot ``b``'s full trajectory state to the host.
 
         Reads happen between ticks (single-threaded contract), so the
-        slices observe a settled state; numpy copies preserve the exact
-        bits (bfloat16 included, via ml_dtypes)."""
-        slot = self._slots[b]
-        if slot is None:
+        slices observe a settled state."""
+        if self._slots[b] is None:
             raise ValueError(f"slot {b} is not resident")
-        lo, hi = b * self._rps, (b + 1) * self._rps
-        hist = (np.asarray(self._hist2[:, lo:hi])
-                if self._hist2 is not None else None)
-        return SlotCheckpoint(
-            request_id=slot.req.request_id, k=slot.k,
-            x_rows=np.asarray(self._x2[lo:hi]), hist_rows=hist,
-            previews=slot.previews, pool_id=self.pool_id, taken_t=now)
+        return self._checkpoint(b, self._x2, self._hist2, now)
 
     def snapshot_slots(self,
                        now: Optional[float] = None) -> List[SlotCheckpoint]:
-        """Checkpoint every resident slot (the supervisor's sweep)."""
+        """Checkpoint every resident slot (the supervisor's sweep).
+
+        The whole state (``_x2``, and ``_hist2`` on multistep engines)
+        comes to the host in ONE ``device_get`` per sweep, however many
+        slots are resident, and each checkpoint is cut from that host
+        copy: the same bits as ``snapshot_slot``, one transfer."""
         with self.obs.span(self._span["checkpoint"]):
-            return [self.snapshot_slot(b, now) for b, s in
-                    enumerate(self._slots) if s is not None]
+            resident = [b for b, s in enumerate(self._slots)
+                        if s is not None]
+            if not resident:
+                return []
+            x2, hist2 = jax.device_get((self._x2, self._hist2))
+            self._c_ck_fetches.inc()
+            return [self._checkpoint(b, x2, hist2, now) for b in resident]
 
     def evict_residents(self) -> List[SampleRequest]:
         """Free every resident slot and hand back its request (no terminal

@@ -9,6 +9,7 @@ EDF ordering are exact, not timing-dependent.
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -134,6 +135,48 @@ def test_snapshot_resume_is_bit_identical():
     assert out.S == 8
 
 
+def _same_bits(a, b):
+    if a is None or b is None:
+        return a is None and b is None
+    return a.dtype == b.dtype and a.shape == b.shape and \
+        a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("kw,order", [
+    (dict(), 1), (dict(max_order=2), 2), (dict(dtype=jnp.bfloat16), 1)],
+    ids=["f32-order1", "f32-order2", "bf16"])
+def test_snapshot_slots_one_fetch_matches_per_slot_reads(kw, order):
+    eng = _engine(slots=4, **kw)
+    fetches = eng.obs.registry.get("engine_checkpoint_fetches_total")
+    assert eng.snapshot_slots() == [] and fetches.value == 0
+    rid, t = 0, 0.0
+    for n_resident in (1, 3, 4):
+        while eng.active < n_resident:          # staggered admissions:
+            eng.submit(SampleRequest(request_id=rid, S=20, seed=rid,
+                                     solver_order=order), now=t)
+            eng.tick(now=t)                     # residents differ in k
+            rid, t = rid + 1, t + DT
+        before = fetches.value
+        cks = eng.snapshot_slots(now=t)
+        assert fetches.value == before + 1
+        resident = eng.resident_requests()
+        assert [ck.request_id for ck in cks] == \
+            [r.request_id for _, r in resident]
+        for (b, _), ck in zip(resident, cks):
+            one = eng.snapshot_slot(b, now=t)
+            assert (ck.k, ck.previews, ck.pool_id, ck.taken_t) == \
+                (one.k, one.previews, one.pool_id, one.taken_t)
+            assert ck.k == eng.resident_step(b)
+            assert _same_bits(ck.x_rows, one.x_rows)
+            assert _same_bits(ck.hist_rows, one.hist_rows)
+            assert (ck.hist_rows is None) == (eng.max_order == 1)
+            for arr in (ck.x_rows, ck.hist_rows):   # owns one slot only
+                if arr is not None:
+                    assert arr.base is None or arr.base.size == arr.size
+        assert fetches.value == before + 1      # per-slot reads: uncounted
+    assert len({ck.k for ck in cks}) > 1
+
+
 def test_resume_rejects_out_of_range_k():
     eng = _engine()
     bad = SampleRequest(request_id=1, S=4, seed=0)
@@ -177,6 +220,35 @@ def test_quarantine_contains_fault_and_work_completes_elsewhere():
     assert sup["migrated"] + sup["restarted"] >= 1   # residents moved
     # migrated requests finished on the surviving pool
     assert any(e["pool_id"] == 1 for e in events)
+
+
+def test_quarantine_after_batched_sweep_migrates_bit_identically():
+    # the quarantine scenario above with a sweep every 2 ticks: pool 0's
+    # sweep fetches both residents at once, its tick 3 faults, and the
+    # requests resumed from that sweep's snapshots finish elsewhere with
+    # the uninterrupted run's exact bits (eta=0, order 1)
+    ref = {}
+    for i in range(4):
+        ref_core, ev = _core(pools=1, supervise=False), []
+        _submit(ref_core, ev, S=8, seed=i)
+        _run(ref_core)
+        ref[i] = np.asarray(ev[0]["x0"])
+    inj = FaultInjector(FaultPlan([
+        Fault(kind="tick-error", pool=0, tick=3)]))
+    core = _core(pools=2, injector=inj, checkpoint_every=2,
+                 breaker=BreakerPolicy(backoff_pumps=2, probe_ticks=1))
+    events = []
+    seed_of = {_submit(core, events, S=8, seed=i): i for i in range(4)}
+    _run(core)
+    assert [e["event"] for e in events] == ["result"] * 4
+    sup = core.supervisor.stats()
+    assert sup["quarantines"] == 1 and sup["migrated"] == 2
+    fetches = sum(p.engine.obs.registry.get(
+        "engine_checkpoint_fetches_total").value for p in core.fleet.pools)
+    assert 1 <= fetches < sup["checkpoints_taken"]   # sweeps of 2 slots
+    for e in events:
+        assert np.array_equal(np.asarray(e["x0"]),
+                              ref[seed_of[e["request_id"]]])
 
 
 def test_supervised_happy_path_matches_unsupervised():
