@@ -39,13 +39,15 @@ def window_s(run) -> float:
 
 
 def slot_step_flops(run) -> float:
-    """Operations of the U-Net evaluations the window's ticks made."""
-    from flops import unet_flops
-    return sum(delta(run, "slot_steps")) * unet_flops(run.config)
+    """Operations of the eps evaluations the window's ticks made: the
+    configuration's trunk's ``eps_flops`` per slot-step."""
+    from reference import trunk
+    return sum(delta(run, "slot_steps")) * trunk(run.config).eps_flops(
+        run.config)
 
 
 def step_mfu(run):
-    """The whole tick's share of the chips' peak, in %: U-Net operations of
+    """The whole tick's share of the chips' peak, in %: eps operations of
     every slot-step the window made over window seconds times the bf16
     peak times the chips."""
     if run.peaks is None:

@@ -11,16 +11,50 @@ The cell (``BENCHMARK.json`` ``workloads``) names a configuration
 ``chipbench/metrics/<metric>.py``. Nothing here names a cell.
 
 A run: weights from ``--seed`` on the device in one jitted call; the
-program's gateway (``launch.serve.build_unet_gateway``: HTTP/SSE front
-door -> GatewayCore -> fleet -> scheduler tick -> U-Net trunk + step
-kernel) on the cell's chips; a load generator in a process of its own
-(``client.py``) serves one full warm round, then the window. Set-up,
-compiles included, ends when the window opens; a compile inside the window
-fails the run. After the window: the metrics, the device's memory peak,
-then the program is freed and the eta = 0 samples of a draw of the window's
-requests are compared with the plain float32 reference
-(``reference.py``). ``--trace 1`` records a profiler trace of the window
-and reports the per-layer metrics instead of the end-to-end ones.
+program's gateway (HTTP/SSE front door -> GatewayCore -> fleet ->
+scheduler tick -> eps trunk + step kernel) on the cell's chips; a load
+generator in a process of its own (``client.py``) serves one full warm
+round, then the window. Set-up, compiles included, ends when the window
+opens; a compile inside the window fails the run. After the window: the
+metrics, the device's memory peak, then the program is freed and the
+eta = 0 samples of a draw of the window's requests are compared with the
+plain float32 reference (``reference.py``). ``--trace 1`` records a
+profiler trace of the window and reports the per-layer metrics instead of
+the end-to-end ones.
+
+The eps trunk is resolved by name as well: a configuration's ``"trunk"``
+key (``"unet"`` where it has none) names ``chipbench/trunks/<trunk>.py``,
+which provides
+
+  make_params(cfg, seed)   the weights, on the device in one jitted call
+                           from the seed, in the program's key layout
+  build_gateway(cfg, params, *, devices, pools, slots, obs, **engine_kw)
+                           the program's GatewayCore for the cell: ``pools``
+                           slot pools of ``slots`` on ``devices``, serving
+                           ``params`` under ``cfg["name"]``; ``engine_kw``
+                           reaches every engine
+  sample_shape(cfg)        one request's sample shape
+  eps_net(params, cfg, x, t)
+                           the plain reference eps, x (N, *sample_shape),
+                           t (N,) integer timesteps; imports nothing of the
+                           program
+  eps_flops(cfg)           operations of one eps evaluation of one sample
+
+``reference.py`` runs the sampler over ``eps_net`` (float64 update on the
+host), ``flops.slot_rows`` lays out ``sample_shape``, and
+``measure.slot_step_flops`` counts ``eps_flops``. So a configuration of a
+new trunk is new files only:
+
+  chipbench/trunks/<trunk>.py               the module above
+  chipbench/configs/<config>.json           its sizes, with "trunk"
+  chipbench/traffic/<traffic>.json          its traffic mix
+  chipbench/checks/<workload>.json          its correctness limits
+  chipbench/metrics/<metric>.py             a reader per new metric
+  BENCHMARK.json                            entries in configs, workloads
+                                            and the metrics' workloads
+
+(``metrics/trunk_roofline.py`` counts U-Net operations; a new trunk's
+roofline is a reader of its own over ``eps_flops``.)
 
 The last line of standard output is one JSON object (``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, ``breakdown`` with
@@ -103,11 +137,6 @@ def engine_options(traffic: Dict) -> Dict:
                 max_order=max(int(s.get("order", 1)) for s in mix),
                 preview=any(int(s.get("preview_every", 0)) > 0
                             for s in mix))
-
-
-# the configuration keys that are the program's UNetConfig fields
-UNET_KEYS = ("in_channels", "base_width", "width_mults", "n_res_blocks",
-             "attn_levels", "time_dim", "groups")
 
 
 # ------------------------------------------------------------ the chip
@@ -373,8 +402,6 @@ def build(cell, seed: int, trace: bool, *, require_tpu: bool = True,
     devs = find_chips(cell.chips, require_tpu)[:cell.chips]
     sys.path.insert(0, str(root / "src"))
     try:
-        from repro.launch.serve import build_unet_gateway
-        from repro.models.unet import UNetConfig
         from repro.obs import Observability
     except ImportError as e:
         raise RunFailure(f"the program (src/repro) is not in this checkout "
@@ -382,18 +409,16 @@ def build(cell, seed: int, trace: bool, *, require_tpu: bool = True,
     import reference
 
     cfg = cell.config
+    trunk = reference.trunk(cfg)
     slots = int(cfg["slots_per_pool"])
     pools = int(cfg["pools_per_chip"]) * cell.chips
     opts = engine_options(cell.traffic)
     log(f"device {devs[0].device_kind!r} x{len(devs)}, cache {cache}, "
-        f"{cfg['name']}: {pools} pool(s) x {slots} slots, {opts}")
-    ucfg = UNetConfig(**{k: (tuple(cfg[k]) if isinstance(cfg[k], list)
-                             else cfg[k]) for k in UNET_KEYS})
-    core = build_unet_gateway(
-        ucfg, cfg["image_size"],
-        {cfg["name"]: reference.make_params(cfg, seed)},
-        T=cfg["schedule"]["T"], pools_per_model=pools, slots=slots,
-        devices=devs, obs=Observability(profile=trace), **opts)
+        f"{cfg['name']} ({cfg.get('trunk', 'unet')} trunk): {pools} "
+        f"pool(s) x {slots} slots, {opts}")
+    core = trunk.build_gateway(
+        cfg, trunk.make_params(cfg, seed), devices=devs, pools=pools,
+        slots=slots, obs=Observability(profile=trace), **opts)
     for p in core.fleet.pools:
         if p.engine.interpret and require_tpu:
             raise RunFailure(f"pool {p.pool_id} runs its kernels in "

@@ -55,14 +55,15 @@ def test_reference_layout_is_the_programs(name):
     cfg = config(name)
     shapes = jax.eval_shape(lambda: unet.init_params(jax.random.PRNGKey(0),
                                                      unet_config(cfg)))
-    ours = reference._layout(cfg)
+    trunk = reference.trunk(cfg)
+    ours = trunk._layout(cfg)
     theirs = jax.tree.map(lambda a: tuple(a.shape), shapes)
-    is_leaf = reference._is_shape
+    is_leaf = trunk._is_shape
     assert (jax.tree.structure(ours, is_leaf=is_leaf)
             == jax.tree.structure(theirs, is_leaf=is_leaf))
     assert (jax.tree.leaves(ours, is_leaf=is_leaf)
             == jax.tree.leaves(theirs, is_leaf=is_leaf))
-    assert reference.param_count(cfg) == cfg["params"]
+    assert trunk.param_count(cfg) == cfg["params"]
 
 
 @pytest.mark.parametrize("name,slots", [("cifar10-unet", 32),
